@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import get_config, list_archs
 from repro.launch import hloanalysis
 from repro.launch.mesh import make_production_mesh
@@ -210,7 +209,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, outdir: Path,
     kind, seq, batch = info["kind"], info["seq"], info["batch"]
     cfg = _dryrun_cfg(get_config(arch), kind)
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
-    compat.set_mesh(mesh)
+    jax.set_mesh(mesh)
     n_dev = mesh.devices.size
     rules = sharding.make_rules(mesh)
     api = model_api.build(cfg)

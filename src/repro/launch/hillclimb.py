@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import get_config
 from repro.launch import hloanalysis
 from repro.launch.dryrun import (SHAPES, WHISPER_DEC_PREFILL,
@@ -133,7 +132,7 @@ def run_variant(arch: str, shape_name: str, variant: str,
     kind, seq, batch = info["kind"], info["seq"], info["batch"]
     cfg = _dryrun_cfg(get_config(arch), kind)
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
-    compat.set_mesh(mesh)
+    jax.set_mesh(mesh)
     rules = sharding.make_rules(mesh)
     ep = _ep_for(cfg, mesh, rules)
     cfg, ep = VARIANTS[variant](cfg, ep)

@@ -7,7 +7,8 @@ import to obtain placeholder devices.
 """
 from __future__ import annotations
 
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):

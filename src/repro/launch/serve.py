@@ -5,9 +5,11 @@ Two modes:
     analytical v5e executor — the configuration used for the paper-figure
     benchmarks; scales to hundreds of workers.
   * ``--mode real``: drives the same ``ClusterScheduler`` against REAL JAX
-    model execution on this host (reduced config) through the
-    ``RealJaxBackend``, proving the scheduler is executor-agnostic end to
-    end.
+    model execution on this host's devices through the ``RealJaxBackend``
+    — the published config at its full widths (``--smoke`` swaps in the
+    reduced same-family config for CPU runs). Each worker holds a slotted
+    KV cache of ``--max-slots`` x ``--max-len`` positions, and every
+    request is clipped to fit one slot (``clip_to_cache``).
 
 ``--json`` prints one stable, versioned metrics object on stdout
 (``schema_version`` bumps on breaking changes; keys are sorted) so scripts
@@ -38,8 +40,9 @@ key keeps its meaning.
 Examples:
   PYTHONPATH=src python -m repro.launch.serve --arch internlm-20b \
       --policy tropical --rate 2.0 --duration 120
-  PYTHONPATH=src python -m repro.launch.serve --mode real --policy tropical \
-      --rate 2.0 --duration 20 --workers 2
+  PYTHONPATH=src python -m repro.launch.serve --mode real --smoke \
+      --arch qwen2-1.5b --policy tropical --rate 2.0 --duration 20 \
+      --workers 2 --max-slots 8 --max-len 256
   PYTHONPATH=src python -m repro.launch.serve --scenario mixture --json
 """
 from __future__ import annotations
@@ -140,6 +143,15 @@ def _classes_scenario(classes: list[dict], cost) -> "object":
     return Scenario("slo-classes", tuple(comps))
 
 
+def clip_to_cache(reqs, max_len: int) -> None:
+    """Fit every request into one KV cache slot of ``max_len`` positions:
+    the output keeps at most half the slot and the prompt takes the rest,
+    so ``prompt_len + output_len <= max_len``."""
+    for r in reqs:
+        r.output_len = min(r.output_len, max_len // 2)
+        r.prompt_len = min(r.prompt_len, max_len - r.output_len)
+
+
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm-20b")
@@ -147,6 +159,15 @@ def build_parser() -> argparse.ArgumentParser:
                     choices=["vllm", "sarathi", "distserve", "tropical",
                              "tropical++"])
     ap.add_argument("--mode", default="sim", choices=["sim", "real"])
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the arch's reduced same-family config (CPU "
+                         "test scale) instead of the published one")
+    ap.add_argument("--max-slots", type=int, default=16,
+                    help="real mode: KV cache slots (concurrent requests) "
+                         "per worker")
+    ap.add_argument("--max-len", type=int, default=2048,
+                    help="real mode: KV cache positions per slot; each "
+                         "request is clipped to prompt + output <= this")
     ap.add_argument("--backend", default="cost-model",
                     choices=["cost-model", "trace-replay"],
                     help="sim-mode execution backend: 'cost-model' "
@@ -235,6 +256,10 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
                  "(omit the flag to disable online recalibration)")
     if args.host_kv_gb < 0:
         ap.error("--host-kv-gb must be >= 0 (0 disables the host tier)")
+    if args.max_slots < 1:
+        ap.error("--max-slots must be >= 1")
+    if args.max_len < 2:
+        ap.error("--max-len must be >= 2 (one prompt + one output token)")
 
     from repro.configs import get_config, get_smoke
     from repro.serving.costmodel import WorkerSpec
@@ -264,12 +289,8 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
                      "replay recorded traffic under these tiers, or drop "
                      "one of the flags)")
 
-    if args.mode == "real":
-        cfg = get_smoke(args.arch)
-        spec = WorkerSpec(tp=1)
-    else:
-        cfg = get_config(args.arch)
-        spec = WorkerSpec(tp=args.tp)
+    cfg = (get_smoke if args.smoke else get_config)(args.arch)
+    spec = WorkerSpec(tp=1 if args.mode == "real" else args.tp)
 
     sim, cost = build_cluster(
         cfg, args.policy, n_workers=args.workers, worker_spec=spec,
@@ -308,17 +329,19 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
                                seed=args.seed)
         feed = ((r.arrival_time, r) for r in trace) if streaming else trace
 
+    execs = None
     if streaming:
         sim.add_replay(feed)
     else:
         if args.mode == "real":
             import jax
+            from repro.compile_cache import use_compile_cache
             from repro.serving.executor import ClusterRealExecutors
-            for r in feed:   # shrink to smoke scale
-                r.prompt_len = min(r.prompt_len, 48)
-                r.output_len = min(r.output_len, 16)
-            execs = ClusterRealExecutors(cfg, args.workers, max_slots=8,
-                                         max_len=128,
+            use_compile_cache()
+            clip_to_cache(feed, args.max_len)
+            execs = ClusterRealExecutors(cfg, args.workers,
+                                         max_slots=args.max_slots,
+                                         max_len=args.max_len,
                                          rng=jax.random.PRNGKey(args.seed))
             sim.sched.backend = execs.as_backend(clock="wall")
         sim.add_trace(feed)
@@ -368,6 +391,8 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
                    predictor_decode_scale=round(pred.decode_scale, 4))
     if sim.sched.rebalancer is not None:
         row.update(role_transitions=len(sim.sched.rebalancer.transitions))
+    if execs is not None:
+        row.update(_real_counters(execs, feed, args))
     if sim.sched.drift_monitor is not None:
         lo, hi = sim.sched.drift_monitor.gamma_range()
         row.update(recalibrate_every=sim.sched.drift_monitor.every,
@@ -385,6 +410,27 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
                             else f"{ck}={cv}" for ck, cv in cm.items())
             print(f"{'class ' + name:>22}: {cols}")
     return row
+
+
+def _real_counters(execs, reqs, args) -> dict:
+    """What a real-mode run served and compiled, and where it ran."""
+    import jax
+    generated: dict[int, int] = {}
+    for e in execs.execs.values():
+        for rid, toks in e.generated.items():
+            generated[rid] = max(generated.get(rid, 0), len(toks))
+    dev = jax.devices()[0]
+    out = dict(platform=dev.platform, device_kind=dev.device_kind,
+               device_count=len(jax.devices()),
+               max_slots=args.max_slots, max_len=args.max_len,
+               prompt_tokens=sum(r.prompt_len for r in reqs),
+               max_prompt_len=max((r.prompt_len for r in reqs), default=0),
+               output_tokens=sum(r.output_len for r in reqs),
+               generated_tokens=sum(generated.values()))
+    if execs.kernels is not None:
+        out.update(prefill_traces=execs.kernels.prefill_traces,
+                   decode_traces=execs.kernels.decode_traces)
+    return out
 
 
 if __name__ == "__main__":

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 
 from repro.models.layers import ModelConfig, _dense_init, _activate
 
@@ -220,7 +219,7 @@ def apply_moe_ep(params, x: jax.Array, cfg: ModelConfig, info: EPInfo) -> jax.Ar
 
     body = _moe_ep_allgather_local if info.ep_mode == "allgather" \
         else _moe_ep_local
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(body, cfg=cfg, info=info),
         mesh=mesh,
         in_specs=(pspecs, bspec),

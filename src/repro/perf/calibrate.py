@@ -23,8 +23,11 @@ each cell's measured excess for γ, returning a bucketed
 resulting model: the ROADMAP's "batched roofline with measured MFU"
 backend. Off-TPU (CPU CI, interpret-mode Pallas) the measured fractions
 are tiny but still well-defined — they are clamped into ``(0, 1]`` and
-the backend remains exercisable end-to-end; on a real TPU the same code
-path yields deployment-grade constants.
+the backend remains exercisable end-to-end. Compiled, the kernels run on
+the device the ``HardwareSpec`` describes: a device whose ``device_kind``
+the spec does not list raises (its peaks are unknown), and so does a
+fraction above ``_FRAC_CEIL`` (no chip beats its own peak; such a reading
+means the flop/byte accounting or the peak is wrong).
 """
 from __future__ import annotations
 
@@ -38,6 +41,7 @@ from repro.perf.hardware import (HardwareSpec, InterferenceTable, V5E,
 from repro.perf.model import CostModel
 
 _MFU_FLOOR = 1e-6        # interpret-mode measurements stay valid fractions
+_FRAC_CEIL = 1.05        # compiled readings above this are accounting bugs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,8 +69,29 @@ class KernelCalibration:
     mfu_gemm_decode: float = 0.0
 
 
-def _clamp_frac(x: float) -> float:
-    return min(max(x, _MFU_FLOOR), 1.0)
+def _frac(x: float, interpret: bool, what: str) -> float:
+    """A measured fraction of a peak. Interpret mode keeps it a valid
+    fraction in ``[_MFU_FLOOR, 1]``; compiled, timing noise up to
+    ``_FRAC_CEIL`` reads as 1 and anything above raises."""
+    if interpret:
+        return min(max(x, _MFU_FLOOR), 1.0)
+    if x > _FRAC_CEIL:
+        raise ValueError(
+            f"measured {what} = {x:.3f} of peak exceeds {_FRAC_CEIL}: the "
+            "flop/byte accounting or the HardwareSpec peak is wrong")
+    return min(x, 1.0)
+
+
+def _check_device(hw: HardwareSpec, interpret: bool) -> None:
+    """Compiled, the kernels must run on a device the spec's peaks
+    describe."""
+    import jax
+    kind = jax.devices()[0].device_kind
+    if not interpret and kind not in hw.device_kinds:
+        raise ValueError(
+            f"device_kind {kind!r} is not one {hw.name} describes "
+            f"{hw.device_kinds}: its peak FLOP/s and HBM bandwidth are "
+            "unknown, so no measured fraction of them can be formed")
 
 
 def _time_fn(fn, repeats: int) -> float:
@@ -175,8 +200,8 @@ def _gemm_case(rng, dtype, seq: int, batch: int):
 
     def prefill_call():
         # thread the cache: donate_argnums consumes the argument buffer
-        toks, state["cache"] = pfn(params, state["cache"], chunk, slots,
-                                   starts, takes)
+        toks, _, state["cache"] = pfn(params, state["cache"], chunk, slots,
+                                      starts, takes)
         return toks
 
     lengths = jnp.full((batch,), seq, jnp.int32)
@@ -212,7 +237,9 @@ def calibrate_hardware(hw: HardwareSpec = V5E, *,
 
     Shapes default small enough that interpret-mode (non-TPU) calibration
     finishes in seconds; on a TPU pass serving-sized shapes
-    (seq=2048, head_dim=128, page_size=64) for representative numbers."""
+    (seq=2048, head_dim=128, page_size=64) for representative numbers.
+    Compiled (``interpret=False``), the device must be one ``hw``
+    describes (``HardwareSpec.device_kinds``); otherwise ValueError."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -220,31 +247,36 @@ def calibrate_hardware(hw: HardwareSpec = V5E, *,
     device = jax.default_backend()
     if interpret is None:
         interpret = device != "tpu"
+    _check_device(hw, interpret)
     rng = np.random.default_rng(0)
     dtype = jnp.float32 if interpret else jnp.bfloat16
 
     prefill_fn, p_flops, _ = _prefill_case(rng, dtype, seq, heads, head_dim,
                                            interpret)
     t_p = _time_fn(prefill_fn, repeats)
-    mfu_p = _clamp_frac(p_flops / (t_p * hw.peak_flops))
+    mfu_p = _frac(p_flops / (t_p * hw.peak_flops), interpret, "mfu_prefill")
 
     decode_fn, d_flops, d_bytes = _decode_case(
         rng, dtype, batch, heads, head_dim, page_size, pages_per_seq,
         interpret)
     t_d = _time_fn(decode_fn, repeats)
-    mfu_d = _clamp_frac(d_flops / (t_d * hw.peak_flops))
-    bw_eff = _clamp_frac(d_bytes / (t_d * hw.hbm_bw))
+    mfu_d = _frac(d_flops / (t_d * hw.peak_flops), interpret, "mfu_decode")
+    bw_eff = _frac(d_bytes / (t_d * hw.hbm_bw), interpret, "bw_eff")
 
     gp_t = gd_t = gp_f = gd_f = mfu_gp = mfu_gd = 0.0
     if gemm:
         gp_fn, gp_f, gd_fn, gd_f = _gemm_case(rng, dtype, seq, batch)
         gp_t = _time_fn(gp_fn, repeats)
         gd_t = _time_fn(gd_fn, repeats)
-        mfu_gp = _clamp_frac(gp_f / (gp_t * hw.peak_flops))
-        mfu_gd = _clamp_frac(gd_f / (gd_t * hw.peak_flops))
+        mfu_gp = _frac(gp_f / (gp_t * hw.peak_flops), interpret,
+                       "mfu_gemm_prefill")
+        mfu_gd = _frac(gd_f / (gd_t * hw.peak_flops), interpret,
+                       "mfu_gemm_decode")
         # blended phase MFU: one combined workload, one combined clock
-        mfu_p = _clamp_frac((p_flops + gp_f) / ((t_p + gp_t) * hw.peak_flops))
-        mfu_d = _clamp_frac((d_flops + gd_f) / ((t_d + gd_t) * hw.peak_flops))
+        mfu_p = _frac((p_flops + gp_f) / ((t_p + gp_t) * hw.peak_flops),
+                      interpret, "mfu_prefill")
+        mfu_d = _frac((d_flops + gd_f) / ((t_d + gd_t) * hw.peak_flops),
+                      interpret, "mfu_decode")
 
     cal = KernelCalibration(
         mfu_prefill=mfu_p, mfu_decode=mfu_d, bw_eff=bw_eff,
@@ -322,6 +354,7 @@ def calibrate_interference(hw: HardwareSpec = V5E, *,
     device = jax.default_backend()
     if interpret is None:
         interpret = device != "tpu"
+    _check_device(hw, interpret)
     rng = np.random.default_rng(0)
     dtype = jnp.float32 if interpret else jnp.bfloat16
     peak_c = hw.peak_flops

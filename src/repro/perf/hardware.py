@@ -8,7 +8,10 @@ admission, decode routing, role rebalancing — must price it on the
 ``HardwareSpec`` by the tensor-parallel degree of a model replica.
 
 Constants follow the assignment hardware: TPU v5e, 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI per chip.
+819 GB/s HBM, ~50 GB/s/link ICI per chip (Google Cloud documentation,
+"TPU v5e"). ``HardwareSpec.device_kinds`` names the JAX ``device_kind``
+strings those peaks belong to; measuring against them on another device
+is an error, not a default.
 """
 from __future__ import annotations
 
@@ -143,6 +146,8 @@ class HardwareSpec:
     # bucket by ``repro.perf.calibrate.calibrate_interference`` and kept
     # current online by ``repro.perf.recalibrate.DriftMonitor``.
     interference: "float | InterferenceTable" = 0.0
+    # ``jax.Device.device_kind`` of the chips these peaks describe
+    device_kinds: tuple = ("TPU v5 lite",)
 
     def slowed(self, factor: float) -> "HardwareSpec":
         """A ``factor``x-slower variant of this spec (straggler modelling):

@@ -42,8 +42,7 @@ fall back to the scalar reference even under ``batched=True``.
 from __future__ import annotations
 
 import time
-import warnings
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -54,11 +53,6 @@ from repro.models import api as model_api
 from repro.perf import CostModel
 from repro.sched.backend import SlotExhausted
 from repro.serving.engine import IterationPlan, Worker
-
-# CPU jax cannot honour buffer donation; the (once-per-compile) warning is
-# expected off-accelerator and would otherwise pollute every test run
-warnings.filterwarnings(
-    "ignore", message="Some donated buffers were not usable")
 
 _BUCKET_FLOOR = 32
 
@@ -97,15 +91,36 @@ def _slice_row(tree, idx):
             (a.shape[0], 1) + a.shape[2:]), tree)
 
 
+def _put_row(tree, row, idx):
+    """Write a one-slot ``row`` (from ``_slice_row``) at slot ``idx``."""
+    return jax.tree.map(
+        lambda a, r: lax.dynamic_update_slice(
+            a, r.astype(a.dtype), (0, idx) + (0,) * (a.ndim - 2)),
+        tree, row)
+
+
+# migration: slice a slot row on the source device, write it on the
+# destination's (the cache argument is donated: updated in place)
+_take_row = jax.jit(_slice_row)
+_give_row = jax.jit(_put_row, donate_argnums=0)
+
+
+def _on_device(device, make):
+    """``make()``'s arrays, created on and committed to ``device``."""
+    with jax.default_device(device):
+        return jax.device_put(make(), device)
+
+
 class ExecutorKernels:
     """Jitted, slot-indexed entry points over one cache geometry, shared
     by every executor in a cluster (replicas have identical shapes, so
     each (bucket, rows) signature compiles exactly once per process).
 
     ``prefill_traces`` / ``decode_traces`` increment only when jax
-    actually traces (= compiles) an entry point — the compile-count
-    regression tests pin them to the bucket grid, not to the number of
-    distinct chunk lengths seen.
+    actually traces an entry point — the compile-count regression tests
+    pin them to the bucket grid, not to the number of distinct chunk
+    lengths seen. Executors on different devices share the traces; each
+    device compiles its own executable.
     """
 
     def __init__(self, api, max_slots: int, max_len: int):
@@ -117,7 +132,6 @@ class ExecutorKernels:
         self.decode_traces = 0
         self._prefill_fns: dict[tuple[int, int], object] = {}
         self._decode = None
-        self._copy = None
 
     def bucket_for(self, take: int) -> int:
         for b in self.buckets:
@@ -130,7 +144,8 @@ class ExecutorKernels:
     def prefill_fn(self, bucket: int, rows: int):
         """Batched bucketed prefill: gathers ``rows`` slot views, runs one
         padded ``prefill_chunk``, scatters the rows back in place, and
-        samples every row's next token on device."""
+        samples every row's next token on device. Returns ``(tokens,
+        last-token logits, cache)``; the executor reads only the tokens."""
         key = (bucket, rows)
         fn = self._prefill_fns.get(key)
         if fn is None:
@@ -144,13 +159,9 @@ class ExecutorKernels:
                 logits, view = api.prefill_chunk(
                     params, view, chunk, starts, take=takes)
                 for i in range(rows):
-                    cache = jax.tree.map(
-                        lambda a, r: lax.dynamic_update_slice(
-                            a, r.astype(a.dtype),
-                            (0, slots[i]) + (0,) * (a.ndim - 2)),
-                        cache, _slice_row(view, i))
+                    cache = _put_row(cache, _slice_row(view, i), slots[i])
                 toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return toks, cache
+                return toks, logits, cache
 
             fn = jax.jit(step, donate_argnums=1)
             self._prefill_fns[key] = fn
@@ -169,31 +180,17 @@ class ExecutorKernels:
             self._decode = jax.jit(step, donate_argnums=1)
         return self._decode
 
-    @property
-    def copy_fn(self):
-        """Device-to-device KV slot copy (migration fast path)."""
-        if self._copy is None:
-
-            def step(dst, src, dslot, sslot):
-                return jax.tree.map(
-                    lambda a, r: lax.dynamic_update_slice(
-                        a, r.astype(a.dtype),
-                        (0, dslot) + (0,) * (a.ndim - 2)),
-                    dst, _slice_row(src, sslot))
-
-            self._copy = jax.jit(step, donate_argnums=0)
-        return self._copy
-
     # --------------------------------------------------------------- warmup
-    def warmup(self, params) -> None:
+    def warmup(self, params, device) -> None:
         """Compile the (bucket, 1-row) grid + the decode step up front on a
-        throwaway cache, so the first scheduled iterations measure steady-
-        state execution (not compilation) — the durations that feed the
-        OnlinePredictor EWMAs."""
-        cache = self.api.init_cache(self.max_slots, self.max_len)
+        throwaway cache on ``device`` (where ``params`` live), so the first
+        scheduled iterations measure steady-state execution (not
+        compilation) — the durations that feed the OnlinePredictor EWMAs."""
+        cache = _on_device(device, lambda: self.api.init_cache(
+            self.max_slots, self.max_len))
         one = jnp.zeros((1,), jnp.int32)
         for b in self.buckets:
-            _, cache = self.prefill_fn(b, 1)(
+            _, _, cache = self.prefill_fn(b, 1)(
                 params, cache, jnp.zeros((1, b), jnp.int32), one, one,
                 jnp.ones((1,), jnp.int32))
         zeros = jnp.zeros((self.max_slots,), jnp.int32)
@@ -210,18 +207,22 @@ class SimExecutor:
 
 
 class RealExecutor:
-    """One executor per worker; owns params + a slotted cache."""
+    """One executor per worker; owns params + a slotted cache, both on
+    ``device`` (default: JAX's first device)."""
 
     def __init__(self, cfg, rng, max_slots: int = 8, max_len: int = 256,
                  params=None, batched: bool = True, wid: int = 0,
-                 kernels: Optional[ExecutorKernels] = None, owner=None):
+                 kernels: Optional[ExecutorKernels] = None, owner=None,
+                 device=None):
         self.cfg = cfg
         self.api = model_api.build(cfg)
         self.params = params if params is not None else self.api.init(rng)
         self.max_slots = max_slots
         self.max_len = max_len
         self.wid = wid
-        self.cache = self.api.init_cache(max_slots, max_len)
+        self.device = device if device is not None else jax.devices()[0]
+        self.cache = _on_device(
+            self.device, lambda: self.api.init_cache(max_slots, max_len))
         self.free_slots = list(range(max_slots))
         self.slot_of: dict[int, int] = {}
         self.owner = owner                   # cluster rid -> wid registry
@@ -378,7 +379,7 @@ class RealExecutor:
                 slots[i] = slots[0]
                 starts[i] = starts[0]
                 takes[i] = takes[0]
-            toks_dev, self.cache = self.kernels.prefill_fn(bucket, rows)(
+            toks_dev, _, self.cache = self.kernels.prefill_fn(bucket, rows)(
                 self.params, self.cache, jnp.asarray(chunk),
                 jnp.asarray(slots), jnp.asarray(starts), jnp.asarray(takes))
             tok_parts.append(toks_dev)
@@ -427,31 +428,40 @@ class RealExecutor:
 class ClusterRealExecutors:
     """Per-worker RealExecutor registry + shared duration_fn dispatch.
 
-    All replicas share weights AND compiled entry points (identical cache
-    geometry => one jit cache for the cluster), warmed over the bucket
-    grid at construction.
+    Worker ``i`` runs on ``devices[i % len(devices)]`` (default: every
+    device JAX sees, so one worker per chip on a multi-chip host). All
+    replicas share weights — one copy per device — AND compiled entry
+    points (identical cache geometry => one jit cache for the cluster),
+    warmed over the bucket grid on each device at construction.
     """
 
     def __init__(self, cfg, n_workers: int, rng=None, max_slots=8,
-                 max_len=256, batched: bool = True, warmup: bool = True):
+                 max_len=256, batched: bool = True, warmup: bool = True,
+                 devices: Optional[Sequence] = None):
         rng = rng if rng is not None else jax.random.PRNGKey(0)
         api = model_api.build(cfg)
-        params = api.init(rng)   # replicas share weights
+        devices = list(devices) if devices is not None else jax.devices()
+        placed = [devices[i % len(devices)] for i in range(n_workers)]
+        params = jax.jit(api.init)(rng)      # replicas share weights
+        on_dev = {d: jax.device_put(params, d) for d in dict.fromkeys(placed)}
         self.batched = batched
         self._owner: dict[int, int] = {}     # rid -> owning wid
+        self.migrations: list[tuple[int, int, int]] = []  # (rid, src, dst)
         kernels = None
         if batched and api.prefill_chunk is not None and \
                 _uniform_cache(api.init_cache(1, max_len)):
             kernels = ExecutorKernels(api, max_slots, max_len)
         self.kernels = kernels
         self.execs = {
-            i: RealExecutor(cfg, rng, max_slots, max_len, params=params,
-                            batched=batched, wid=i, kernels=kernels,
-                            owner=self._owner)
+            i: RealExecutor(cfg, rng, max_slots, max_len,
+                            params=on_dev[placed[i]], batched=batched, wid=i,
+                            kernels=kernels, owner=self._owner,
+                            device=placed[i])
             for i in range(n_workers)
         }
         if kernels is not None and warmup:
-            kernels.warmup(params)
+            for d, p in on_dev.items():
+                kernels.warmup(p, d)
 
     def duration_fn(self):
         def run(worker: Worker, plan: IterationPlan) -> float:
@@ -467,17 +477,21 @@ class ClusterRealExecutors:
         return RealJaxBackend(self, clock=clock)
 
     def migrate(self, req, src: int, dst: int) -> None:
-        """Move the request across workers. Cache-true families copy the
-        KV slot device-to-device (on TPU this is the ICI transfer);
-        stateful/ring families re-derive it by replaying prefill."""
+        """Move the request across workers. Cache-true families move the
+        KV slot row: sliced on the source device, ``device_put`` to the
+        destination's (across chips, the ICI transfer; a no-op when both
+        workers share a chip), written in place there. Stateful/ring
+        families re-derive it by replaying prefill."""
         se, de = self.execs[src], self.execs[dst]
         de.prompts[req.rid] = se.prompts[req.rid]
         de.generated[req.rid] = list(se.generated[req.rid])
         slot = de._slot(req.rid)      # SlotExhausted surfaces to scheduler
+        self.migrations.append((req.rid, src, dst))
         sslot = se.slot_of.get(req.rid)
         if de.fast and sslot is not None and se.fast:
-            de.cache = de.kernels.copy_fn(
-                de.cache, se.cache, jnp.int32(slot), jnp.int32(sslot))
+            row = jax.device_put(_take_row(se.cache, jnp.int32(sslot)),
+                                 de.device)
+            de.cache = _give_row(de.cache, row, jnp.int32(slot))
             de.lengths[slot] = se.lengths[sslot]
             se.release(req.rid)
             return

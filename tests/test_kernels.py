@@ -37,6 +37,8 @@ PAGED_SWEEP = [
     (2, 16, 4, 128, 64, 4, jnp.float32),    # serving-like tiles
     (3, 8, 2, 64, 16, 8, jnp.bfloat16),
     (2, 8, 8, 128, 64, 2, jnp.bfloat16),
+    (3, 12, 2, 128, 16, 8, jnp.bfloat16),   # qwen2-1.5b GQA (G=6)
+    (2, 12, 2, 128, 64, 4, jnp.bfloat16),
 ]
 
 
@@ -104,6 +106,8 @@ CHUNK_SWEEP = [
     (2, 64, 4, 2, 64, 256, 32, 64, 48, jnp.float32),     # sliding window
     (2, 64, 4, 4, 64, 256, 32, 64, None, jnp.bfloat16),
     (1, 256, 16, 16, 64, 512, 128, 256, None, jnp.bfloat16),
+    (1, 256, 12, 2, 128, 512, 128, 256, None, jnp.bfloat16),  # qwen2 GQA
+    (2, 64, 12, 2, 128, 256, 32, 64, None, jnp.bfloat16),
 ]
 
 

@@ -13,7 +13,8 @@ SCRIPT = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import AxisType, make_mesh, set_mesh
+    from jax import make_mesh, set_mesh
+    from jax.sharding import AxisType
     from repro.models.layers import ModelConfig
     from repro.models import moe as M
 

@@ -263,6 +263,30 @@ def test_calibrate_hardware_measures_sane_fractions():
     assert hw.hbm_bytes == V5E.hbm_bytes and hw.ici_bw == V5E.ici_bw
 
 
+def test_calibrate_compiled_refuses_an_unknown_device_kind(monkeypatch):
+    """Compiled calibration divides by the spec's peaks, so it must run on
+    the device they describe: an unknown device_kind raises before any
+    kernel runs (it used to assume V5E peaks on anything)."""
+    import types
+
+    import jax
+
+    fake = types.SimpleNamespace(platform="tpu", device_kind="TPU v99 fake")
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [fake])
+    with pytest.raises(ValueError, match="device_kind"):
+        calibrate_hardware(V5E, interpret=False)
+
+
+def test_calibrate_compiled_fraction_above_peak_raises():
+    from repro.perf.calibrate import _frac
+
+    assert _frac(1e-9, True, "mfu") == pytest.approx(1e-6)  # interpret floor
+    assert _frac(1.02, False, "mfu") == 1.0       # timing noise at the peak
+    assert _frac(0.3, False, "mfu") == 0.3
+    with pytest.raises(ValueError, match="exceeds"):
+        _frac(1.2, False, "mfu")
+
+
 def test_calibrated_roofline_backend_prices_iterations():
     cfg = get_smoke("deepseek-7b")
     backend = CalibratedRooflineBackend(

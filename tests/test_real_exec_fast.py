@@ -206,3 +206,75 @@ def test_scheduler_turns_slot_exhaustion_into_refusal():
     assert refusals, "slot starvation must show up as dispatch refusals"
     for _, wid, rid in refusals:
         assert wid in (0, 1) and 0 <= rid < len(trace)
+
+
+DEVICES_SCRIPT = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import copy, json
+import jax
+from repro.configs import get_smoke
+from repro.core.request import Request, SLOSpec
+from repro.serving.costmodel import WorkerSpec
+from repro.serving.executor import ClusterRealExecutors
+from repro.serving.simulator import build_cluster
+
+cfg = get_smoke("qwen2-1.5b")
+slo = SLOSpec(ttft=30.0, tpot=5.0)
+trace = [Request(rid=i, arrival_time=0.05 * i, prompt_len=20 + 3 * i,
+                 output_len=6, slo=slo) for i in range(6)]
+
+def run(devices):
+    execs = ClusterRealExecutors(cfg, 2, max_slots=4, max_len=64,
+                                 warmup=False, devices=devices)
+    sim, _ = build_cluster(cfg, "distserve", n_workers=2,
+                           worker_spec=WorkerSpec(tp=1),
+                           record_decisions=True,
+                           backend=execs.as_backend(clock="model"))
+    sim.add_trace(copy.deepcopy(trace))
+    m = sim.run(until=3000.0)
+    streams = {}
+    for e in execs.execs.values():
+        for rid, toks in e.generated.items():
+            if len(toks) > len(streams.get(rid, [])):
+                streams[rid] = list(toks)
+    crossing = sum(execs.execs[s].device != execs.execs[d].device
+                   for _, s, d in execs.migrations)
+    return m.n_finished, sim.decisions, streams, execs.migrations, crossing
+
+two = run(jax.devices())
+one = run(jax.devices()[:1])
+print(json.dumps({"finished": [two[0], one[0]],
+                  "decisions_equal": two[1] == one[1],
+                  "tokens_equal": two[2] == one[2],
+                  "migrations": len(two[3]), "crossing": two[4],
+                  "crossing_one": one[4]}))
+"""
+
+
+def test_workers_on_two_devices_match_one_device():
+    """Worker i runs on device i; a migration moves the KV slot row across
+    devices (device_put + the destination's in-place write). Under the
+    cost-model clock the two-device cluster must make the same decisions
+    and generate the same tokens as the same cluster on one device. Runs
+    in a subprocess with two forced host devices (this process keeps
+    one)."""
+    import json
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    res = subprocess.run([sys.executable, "-c", DEVICES_SCRIPT],
+                         capture_output=True, text=True, timeout=300,
+                         env=env)
+    assert res.returncode == 0, res.stderr[-2000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    assert out["finished"] == [6, 6]
+    assert out["decisions_equal"] and out["tokens_equal"]
+    assert out["migrations"] > 0
+    assert out["crossing"] == out["migrations"]
+    assert out["crossing_one"] == 0

@@ -135,3 +135,37 @@ def test_serve_rejects_bad_scenario_and_classes(capsys):
     with pytest.raises(SystemExit):     # absolute + scale conflict
         serve.main(["--slo-classes", "a:ttft=1,scale=5"])
     capsys.readouterr()
+
+
+def test_serve_real_mode_serves_prompts_past_the_old_cap(monkeypatch,
+                                                          tmp_path):
+    """--mode real runs the published config unless --smoke; the cache
+    geometry comes from --max-slots/--max-len and every request is
+    clipped to fit one slot, not to the former 48-token prompt cap."""
+    from repro.launch import serve
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    row = serve.main(["--mode", "real", "--smoke", "--arch", "qwen2-1.5b",
+                      "--policy", "tropical", "--rate", "2",
+                      "--duration", "6", "--workers", "2",
+                      "--max-slots", "8", "--max-len", "256"])
+    assert row["arch"] == "qwen2-smoke" and row["platform"] == "cpu"
+    assert row["n_finished"] == row["n_total"] > 0
+    assert row["max_prompt_len"] > 48
+    assert row["max_slots"] == 8 and row["max_len"] == 256
+    assert row["generated_tokens"] >= row["output_tokens"] > 0
+    assert row["prefill_traces"] >= 1 and row["decode_traces"] == 1
+
+
+def test_clip_to_cache_fits_one_slot():
+    from repro.core.request import Request, SLOSpec
+    from repro.launch.serve import clip_to_cache
+
+    slo = SLOSpec(ttft=1.0, tpot=0.1)
+    reqs = [Request(rid=0, arrival_time=0.0, prompt_len=5000,
+                    output_len=900, slo=slo),
+            Request(rid=1, arrival_time=0.0, prompt_len=40, output_len=8,
+                    slo=slo)]
+    clip_to_cache(reqs, 1024)
+    assert (reqs[0].prompt_len, reqs[0].output_len) == (512, 512)
+    assert (reqs[1].prompt_len, reqs[1].output_len) == (40, 8)

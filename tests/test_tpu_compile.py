@@ -1,0 +1,127 @@
+"""Compile the served path for a described TPU v5e, without a chip.
+
+The TPU compiler refuses what interpret mode accepts: block shapes off
+the (8, 128) tiling, too much VMEM, a program larger than HBM. These
+tests lower the Pallas kernels and the served step programs for one chip
+of a described ``v5e:2x2`` topology. Nothing runs, so they say nothing
+about results or times.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library, and under several
+test workers only the one given this file may do so.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs import get_config
+from repro.kernels.chunked_prefill import chunked_prefill_attention
+from repro.kernels.paged_attention import paged_attention
+from repro.models import api as model_api
+from repro.serving.executor import ExecutorKernels
+
+HBM_BYTES = 16e9
+# (hq, hkv, d): qwen2-1.5b first, then every kv-head count in {1, 2, 4}
+# with both head dims in {64, 128}
+WIDTHS = [(12, 2, 128), (4, 1, 64), (8, 1, 128), (8, 2, 64), (8, 4, 64),
+          (16, 4, 128)]
+# the page and chunk sizes perf/calibrate.py measures with
+PAGE, PAGES_PER_SEQ, BATCH, SEQ = 16, 8, 4, 256
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:   # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _placed(sharding, tree):
+    return jax.tree.map(
+        lambda a: _sds(sharding, a.shape, a.dtype), tree)
+
+
+def _kernel_hlo(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("hq,hkv,d", WIDTHS)
+def test_paged_attention_compiles_for_v5e(one_chip, hq, hkv, d):
+    s = functools.partial(_sds, one_chip)
+    n_pages = BATCH * PAGES_PER_SEQ + 1
+    hlo = _kernel_hlo(
+        functools.partial(paged_attention, interpret=False),
+        s((BATCH, hq, d), jnp.bfloat16),
+        s((n_pages, PAGE, hkv, d), jnp.bfloat16),
+        s((n_pages, PAGE, hkv, d), jnp.bfloat16),
+        s((BATCH, PAGES_PER_SEQ), jnp.int32), s((BATCH,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("hq,hkv,d", WIDTHS)
+def test_chunked_prefill_compiles_for_v5e(one_chip, hq, hkv, d):
+    s = functools.partial(_sds, one_chip)
+    hlo = _kernel_hlo(
+        functools.partial(chunked_prefill_attention, interpret=False),
+        s((1, SEQ, hq, d), jnp.bfloat16),
+        s((1, 2 * SEQ, hkv, d), jnp.bfloat16),
+        s((1, 2 * SEQ, hkv, d), jnp.bfloat16), s((1,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.fixture(scope="module")
+def qwen2_step(one_chip):
+    """qwen2-1.5b at its published config: entry points and the shapes
+    of its params and a 16-slot x 2048-position cache, on one chip."""
+    api = model_api.build(get_config("qwen2-1.5b"))
+    kernels = ExecutorKernels(api, max_slots=16, max_len=2048)
+    params = _placed(one_chip,
+                     jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    cache = _placed(one_chip, api.cache_spec(16, 2048))
+    return kernels, params, cache
+
+
+def _bytes(compiled) -> float:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_served_decode_step_compiles_and_fits_one_chip(one_chip, qwen2_step):
+    kernels, params, cache = qwen2_step
+    s = functools.partial(_sds, one_chip)
+    compiled = kernels.decode_fn.lower(
+        params, cache, s((16,), jnp.int32), s((16,), jnp.int32)).compile()
+    assert _bytes(compiled) < HBM_BYTES
+
+
+def test_served_prefill_step_compiles_for_v5e(one_chip, qwen2_step):
+    kernels, params, cache = qwen2_step
+    s = functools.partial(_sds, one_chip)
+    one = s((1,), jnp.int32)
+    compiled = kernels.prefill_fn(512, 1).lower(
+        params, cache, s((1, 512), jnp.int32), one, one, one).compile()
+    assert _bytes(compiled) < HBM_BYTES
