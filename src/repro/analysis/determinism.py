@@ -4,7 +4,8 @@ Tropical's headline claims (decision parity sim-vs-real, the CI perf
 gate's attainment numbers) assume a deterministic simulation: the same
 seed must produce the same decision stream on every machine, forever.
 Three hazard classes are forbidden in the decision path (``sched/``,
-``serving/``, ``core/``, ``workload/``) and in ``benchmarks/`` /
+``serving/``, ``core/``, ``workload/``, and ``obs.py``, whose counters
+the decision path writes) and in ``benchmarks/`` /
 ``examples/`` (whose published numbers must replay exactly):
 
 * ``wallclock``     — ``time.time``/``perf_counter``/``monotonic``/
@@ -30,7 +31,8 @@ from repro.analysis.base import Finding, Project, SourceFile, dotted_name
 PASS_ID = "determinism"
 
 SCOPE = ("src/repro/sched/", "src/repro/serving/", "src/repro/core/",
-         "src/repro/workload/", "benchmarks/", "examples/")
+         "src/repro/workload/", "src/repro/obs.py", "benchmarks/",
+         "examples/")
 
 WALLCLOCK_CALLS = frozenset({
     "time.time", "time.time_ns", "time.monotonic", "time.monotonic_ns",

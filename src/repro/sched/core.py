@@ -26,6 +26,7 @@ from __future__ import annotations
 
 from typing import Callable, Optional, Sequence
 
+from repro import obs
 from repro.core.metrics import ServeMetrics, compute_metrics
 from repro.core.policies import Policy
 from repro.core.request import Phase, Request
@@ -135,7 +136,8 @@ class ClusterScheduler:
         self._arm_rebalance(now)
 
     def _try_dispatch(self, req: Request, now: float) -> None:
-        wid = self.policy.dispatch_prefill(req, now)
+        with obs.span("sched.dispatch", rid=req.rid):
+            wid = self.policy.dispatch_prefill(req, now)
         ok = wid is not None and wid in self.workers \
             and self.workers[wid].view.alive
         if self.decisions is not None:
@@ -173,16 +175,17 @@ class ClusterScheduler:
         w = self.workers[wid]
         if self._busy[wid] or not w.view.alive:
             return
-        head = w.peek_prefill(now)
-        rule = self.policy.batch_rule(w.view, now, head)
-        plan = w.compose_iteration(rule, now)
-        if plan.empty:
-            return
-        if self.decisions is not None:
-            self.decisions.append((
-                "iter", wid,
-                tuple(r.rid for r in plan.decode_reqs),
-                tuple((r.rid, t) for r, t in plan.prefill_parts)))
+        with obs.span("sched.compose", wid=wid):
+            head = w.peek_prefill(now)
+            rule = self.policy.batch_rule(w.view, now, head)
+            plan = w.compose_iteration(rule, now)
+            if plan.empty:
+                return
+            if self.decisions is not None:
+                self.decisions.append((
+                    "iter", wid,
+                    tuple(r.rid for r in plan.decode_reqs),
+                    tuple((r.rid, t) for r, t in plan.prefill_parts)))
         try:
             dur = self.backend.run_iteration(w, plan)
         except SlotExhausted as exc:
@@ -192,6 +195,7 @@ class ClusterScheduler:
             # request globally and re-kick the worker with the rest
             self._refuse_prefill(w, plan, exc.rid, now)
             return
+        obs.RECORDER.iteration(plan, wid, now, dur, w.plan_duration(plan))
         self._busy[wid] = True
         self._defer("iter_done", now + dur, (wid, plan, dur))
 
@@ -219,21 +223,22 @@ class ClusterScheduler:
         self._busy[wid] = False
         if not w.view.alive:
             return
-        self._observe(wid, plan, dur)
-        finished_prefills = w.complete_iteration(plan, now, dur)
-        self._record_outcomes(plan, finished_prefills)
-        for req in finished_prefills:
-            self._route_decode(w, req, now)
-        # watermark evictions re-enter global dispatch (re-prefill cost)
-        for req in w.drain_preempted():
-            self.backend.on_finish(req)      # execution state restarts too
-            self._try_dispatch(req, now)
-        # watermark offloads spill to the host tier over the DMA link;
-        # freed HBM may in turn let a parked request come back
-        for req in w.drain_offload_started():
-            self._start_offload(w, req, now)
-        self._maybe_restore(w, now)
-        self._drain_global_queue(now)
+        with obs.span("sched.complete", wid=wid):
+            self._observe(wid, plan, dur)
+            finished_prefills = w.complete_iteration(plan, now, dur)
+            self._record_outcomes(plan, finished_prefills)
+            for req in finished_prefills:
+                self._route_decode(w, req, now)
+            # watermark evictions re-enter global dispatch (re-prefill cost)
+            for req in w.drain_preempted():
+                self.backend.on_finish(req)  # execution state restarts too
+                self._try_dispatch(req, now)
+            # watermark offloads spill to the host tier over the DMA link;
+            # freed HBM may in turn let a parked request come back
+            for req in w.drain_offload_started():
+                self._start_offload(w, req, now)
+            self._maybe_restore(w, now)
+            self._drain_global_queue(now)
         self._kick(wid, now)
         self._arm_rebalance(now)
 

@@ -34,6 +34,7 @@ import math
 
 import numpy as np
 
+from repro import obs
 from repro.core.policies import BatchRule, Policy
 from repro.core.request import Phase, Request
 from repro.core.toggle import Role, WorkerView
@@ -804,6 +805,7 @@ class Worker:
             req.prefill_start = now
             req.phase = Phase.PREFILLING
             self.queue_times[req.rid] = now - req.arrival_time
+            obs.RECORDER.admitted(req.rid, req.arrival_time, now)
             self.view.kv_used_tokens += self._own_state(req, req.prompt_len)
         return True
 

@@ -49,12 +49,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import obs
 from repro.models import api as model_api
 from repro.perf import CostModel
 from repro.sched.backend import SlotExhausted
 from repro.serving.engine import IterationPlan, Worker
 
 _BUCKET_FLOOR = 32
+
+
+def _stamp() -> float:
+    return time.perf_counter()  # lint: allow-wallclock(the real executor times its phases on the host clock)
 
 
 def _bucket_grid(max_len: int) -> tuple[int, ...]:
@@ -151,7 +156,7 @@ class ExecutorKernels:
         if fn is None:
             api = self.api
 
-            def step(params, cache, chunk, slots, starts, takes):
+            def prefill_step(params, cache, chunk, slots, starts, takes):
                 self.prefill_traces += 1     # trace-time only: a jit miss
                 view = jax.tree.map(
                     lambda *xs: jnp.concatenate(xs, axis=1),
@@ -163,7 +168,7 @@ class ExecutorKernels:
                 toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return toks, logits, cache
 
-            fn = jax.jit(step, donate_argnums=1)
+            fn = jax.jit(prefill_step, donate_argnums=1)
             self._prefill_fns[key] = fn
         return fn
 
@@ -172,12 +177,12 @@ class ExecutorKernels:
         if self._decode is None:
             api = self.api
 
-            def step(params, cache, tokens, lengths):
+            def decode_step(params, cache, tokens, lengths):
                 self.decode_traces += 1
                 logits, cache = api.decode(params, cache, tokens, lengths)
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
-            self._decode = jax.jit(step, donate_argnums=1)
+            self._decode = jax.jit(decode_step, donate_argnums=1)
         return self._decode
 
     # --------------------------------------------------------------- warmup
@@ -229,7 +234,6 @@ class RealExecutor:
         self.lengths = np.zeros(max_slots, np.int32)
         self.prompts: dict[int, np.ndarray] = {}     # rid -> prompt tokens
         self.generated: dict[int, list[int]] = {}
-        self.pending_logits: dict[int, np.ndarray] = {}
         self._decode_fn = jax.jit(
             lambda p, c, t, l: self.api.decode(p, c, t, l))
         self.batched = batched
@@ -330,25 +334,45 @@ class RealExecutor:
         for r in plan.decode_reqs:
             self._slot(r.rid)
 
-    def run_plan(self, plan: IterationPlan) -> None:
+    def run_plan(self, plan: IterationPlan) -> float:
         """Execute one composed iteration (either regime), returning after
-        the device is idle."""
-        self.assign_slots(plan)
+        the device is idle. Returns its measured duration, seconds: the sum
+        of the phases it hands to ``obs.RECORDER`` (the scalar regime reads
+        its tokens back inside its calls, so its readback phase is 0)."""
         if self.fast:
-            self._run_plan_fast(plan)
-            return
-        for req, take in plan.prefill_parts:
-            self.run_prefill_chunk(req, take)
-        self.run_decode_batch(plan.decode_reqs)
-        jax.block_until_ready(self.cache)
+            return self._run_plan_fast(plan)
+        t0 = _stamp()
+        with obs.span("backend.prepare", wid=self.wid):
+            self.assign_slots(plan)
+        t1 = _stamp()
+        with obs.span("backend.dispatch"):
+            for req, take in plan.prefill_parts:
+                self.run_prefill_chunk(req, take)
+            self.run_decode_batch(plan.decode_reqs)
+        t2 = _stamp()
+        with obs.span("backend.sync"):
+            jax.block_until_ready(self.cache)
+        t3 = _stamp()
+        return self._record(plan, t0, t1, t2, t3, t3)
 
-    def _run_plan_fast(self, plan: IterationPlan) -> None:
+    @staticmethod
+    def _record(plan: IterationPlan, t0: float, t1: float, t2: float,
+                t3: float, t4: float) -> float:
+        obs.RECORDER.executor_phases(
+            plan, obs.Phases(t1 - t0, t2 - t1, t3 - t2, t4 - t3))
+        return t4 - t0
+
+    def _prepare_calls(self, plan: IterationPlan) -> tuple[list, list]:
+        """The iteration's calls, with their inputs on the device:
+        ``[(bucket, rows, args) | (None, None, args)]`` (a prefill bucket
+        group, or the decode batch) and, per call, how its tokens are read
+        back."""
         groups: dict[int, list] = {}
         for req, take in plan.prefill_parts:
             bucket = self.kernels.bucket_for(take)
             groups.setdefault(bucket, []).append((req, take))
+        calls = []
         pending = []        # (rows-of-Optional[req] | decode pairs)
-        tok_parts = []
         for bucket in sorted(groups):
             parts = groups[bucket]
             rows = _next_pow2(len(parts))
@@ -379,10 +403,9 @@ class RealExecutor:
                 slots[i] = slots[0]
                 starts[i] = starts[0]
                 takes[i] = takes[0]
-            toks_dev, _, self.cache = self.kernels.prefill_fn(bucket, rows)(
-                self.params, self.cache, jnp.asarray(chunk),
-                jnp.asarray(slots), jnp.asarray(starts), jnp.asarray(takes))
-            tok_parts.append(toks_dev)
+            calls.append((bucket, rows, (
+                jnp.asarray(chunk), jnp.asarray(slots), jnp.asarray(starts),
+                jnp.asarray(takes))))
             pending.append(("prefill", finals))
         if plan.decode_reqs:
             dpairs = [(r, self._slot(r.rid)) for r in plan.decode_reqs]
@@ -390,39 +413,54 @@ class RealExecutor:
             lengths = np.array(self.lengths)
             for r, s in dpairs:
                 tokens[s] = self.generated[r.rid][-1]
-            toks_dev, self.cache = self.kernels.decode_fn(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(lengths))
-            tok_parts.append(toks_dev)
+            calls.append((None, None, (jnp.asarray(tokens),
+                                       jnp.asarray(lengths))))
             pending.append(("decode", dpairs))
+        return calls, pending
+
+    def _run_plan_fast(self, plan: IterationPlan) -> float:
+        t0 = _stamp()
+        with obs.span("backend.prepare", wid=self.wid):
+            self.assign_slots(plan)
+            calls, pending = self._prepare_calls(plan)
+        t1 = _stamp()
+        tok_parts = []
+        with obs.span("backend.dispatch"):
+            for bucket, rows, args in calls:
+                if bucket is None:
+                    toks_dev, self.cache = self.kernels.decode_fn(
+                        self.params, self.cache, *args)
+                else:
+                    toks_dev, _, self.cache = self.kernels.prefill_fn(
+                        bucket, rows)(self.params, self.cache, *args)
+                tok_parts.append(toks_dev)
+        t2 = _stamp()
         # exactly one device sync + one device->host transfer per iteration
-        jax.block_until_ready(self.cache)
-        if not tok_parts:
-            return
-        host = np.asarray(tok_parts[0]) if len(tok_parts) == 1 else \
-            np.asarray(jnp.concatenate(tok_parts))
-        off = 0
-        for kind, data in pending:
-            if kind == "prefill":
-                for i, req in enumerate(data):
-                    if req is not None:
-                        self.generated[req.rid].append(int(host[off + i]))
-                off += len(data)
-            else:
-                for r, s in data:
-                    self.generated[r.rid].append(int(host[off + s]))
-                    self.lengths[s] += 1
-                off += self.max_slots
+        with obs.span("backend.sync"):
+            jax.block_until_ready(self.cache)
+        t3 = _stamp()
+        with obs.span("backend.readback"):
+            if tok_parts:
+                host = np.asarray(tok_parts[0]) if len(tok_parts) == 1 \
+                    else np.asarray(jnp.concatenate(tok_parts))
+            off = 0
+            for kind, data in pending:
+                if kind == "prefill":
+                    for i, req in enumerate(data):
+                        if req is not None:
+                            self.generated[req.rid].append(
+                                int(host[off + i]))
+                    off += len(data)
+                else:
+                    for r, s in data:
+                        self.generated[r.rid].append(int(host[off + s]))
+                        self.lengths[s] += 1
+                    off += self.max_slots
+        return self._record(plan, t0, t1, t2, t3, _stamp())
 
     def duration_fn(self):
         """Measured-wall-clock duration_fn for the Simulator."""
-
-        def run(worker: Worker, plan: IterationPlan) -> float:
-            t0 = time.perf_counter()  # lint: allow-wallclock(real executor measures device wall time)
-            self.run_plan(plan)
-            return time.perf_counter() - t0  # lint: allow-wallclock(real executor measures device wall time)
-
-        return run
+        return lambda worker, plan: self.run_plan(plan)
 
 
 class ClusterRealExecutors:
@@ -532,10 +570,7 @@ class RealJaxBackend:
         self.clock = clock
 
     def run_iteration(self, worker: Worker, plan: IterationPlan) -> float:
-        e = self.execs.execs[worker.wid]
-        t0 = time.perf_counter()  # lint: allow-wallclock(real executor measures device wall time)
-        e.run_plan(plan)
-        measured = time.perf_counter() - t0  # lint: allow-wallclock(real executor measures device wall time)
+        measured = self.execs.execs[worker.wid].run_plan(plan)
         return measured if self.clock == "wall" else worker.plan_duration(plan)
 
     def on_finish(self, req) -> None:

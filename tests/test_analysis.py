@@ -70,6 +70,12 @@ def test_determinism_ignores_out_of_scope_files():
     assert _rules(DeterminismPass, {"src/repro/models/layers.py": bad}) == []
 
 
+def test_determinism_covers_the_counters_module():
+    bad = "import time\nT = time.perf_counter()\n"
+    assert _rules(DeterminismPass, {"src/repro/obs.py": bad}) == \
+        ["wallclock"]
+
+
 # -------------------------------------------------------------------- soa
 
 SOA_BAD = """
