@@ -316,12 +316,17 @@ def apply_attention(
     lengths: Optional[jax.Array] = None,          # (B,) for "lengths" masks
     rope: bool = True,
     cross_kv: Optional[tuple[jax.Array, jax.Array]] = None,
+    layer=None,                # index into a stacked kv_cache
 ):
     """Returns (out, new_cache).
 
     * no cache: self-attention over x (prefill / train).
-    * kv_cache (B, Smax, Hkv, D): write new K/V at ``cache_positions``,
-      attend over the cache (decode).
+    * kv_cache (B, Smax, Hkv, D), one layer's: write new K/V at
+      ``cache_positions``, attend over the cache (decode).
+    * kv_cache (L, B, Hkv, Smax, D) with ``layer``: the model's stacked,
+      head-major cache. The new rows are written into layer ``layer`` in
+      place, that layer is read back and attended over; the whole stacked
+      pair is returned.
     * cross_kv: attend over fixed K/V (encoder-decoder cross attention);
       no Q/K rope, no cache update.
     """
@@ -348,18 +353,24 @@ def apply_attention(
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
 
-    if kv_cache is not None:
+    if kv_cache is not None and layer is not None:
+        k_cache = _stacked_cache_write(kv_cache[0], k, layer, cache_positions)
+        v_cache = _stacked_cache_write(kv_cache[1], v, layer, cache_positions)
+        k_all = _layer_view(k_cache, layer)
+        v_all = _layer_view(v_cache, layer)
+        new_cache = (k_cache, v_cache)
+    elif kv_cache is not None:
         k_cache, v_cache = kv_cache
         k_cache = _cache_write(k_cache, k, cache_positions)
         v_cache = _cache_write(v_cache, v, cache_positions)
         k_all, v_all = k_cache, v_cache
         new_cache = (k_cache, v_cache)
-        if k_all.dtype != q.dtype:      # quantised (fp8) cache storage
-            k_all = k_all.astype(q.dtype)
-            v_all = v_all.astype(q.dtype)
     else:
         k_all, v_all = k, v
         new_cache = (k, v)
+    if k_all.dtype != q.dtype:          # quantised (fp8) cache storage
+        k_all = k_all.astype(q.dtype)
+        v_all = v_all.astype(q.dtype)
 
     out = attention_scores(q, k_all, v_all, mask,
                            attn_softcap=cfg.attn_softcap, lengths=lengths,
@@ -388,6 +399,41 @@ def _cache_write(cache: jax.Array, new: jax.Array, write_pos: jax.Array) -> jax.
         return lax.dynamic_update_slice_in_dim(c, n.astype(c.dtype), p, axis=0)
 
     return jax.vmap(upd)(cache, new, write_pos)
+
+
+def _stacked_cache_write(cache: jax.Array, new: jax.Array, layer,
+                         write_pos: jax.Array) -> jax.Array:
+    """cache: (L, B, H, Smax, D); new: (B, Snew, H, D); write_pos: (B,).
+    Row ``b``'s tokens go to ``[layer, b, :, write_pos[b]:+Snew]``, in
+    place on a scan carry or a donated buffer. Callers keep every write
+    inside the cache (the executor pads chunks left).
+
+    A decode step (Snew == 1) writes its B x H head_dim rows with one
+    scatter; a prefill chunk writes each row's (H, Snew, D) block with its
+    own ``dynamic_update_slice``. On a v5e (qwen2-1.5b, 16 x 8192) the
+    per-row loop made a decode step 0.45 ms slower, and a scatter of a
+    2048-token chunk's rows took 12 ms for each of K and V. The scatter
+    index names every axis but head_dim (a wider window makes the TPU
+    compiler relay the cache, or loop over the windows), in (b, h) order:
+    the sorted mark must be true, as the TPU's scatter relies on it."""
+    rows = new.transpose(0, 2, 1, 3).astype(cache.dtype)     # (B, H, Snew, D)
+    b, h, s, _ = rows.shape
+    if s == 1:
+        idx = (layer, jnp.arange(b)[:, None], jnp.arange(h)[None, :],
+               write_pos[:, None])
+        return cache.at[idx].set(rows[:, :, 0], indices_are_sorted=True,
+                                 unique_indices=True)
+    for i in range(b):
+        cache = lax.dynamic_update_slice(
+            cache, rows[i][None, None], (layer, i, 0, write_pos[i], 0))
+    return cache
+
+
+def _layer_view(cache: jax.Array, layer) -> jax.Array:
+    """Layer ``layer`` of a stacked (L, B, H, Smax, D) cache, as the
+    (B, Smax, H, D) that ``attention_scores`` reads."""
+    return lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False) \
+        .transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------

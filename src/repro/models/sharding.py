@@ -189,28 +189,34 @@ def cache_specs(cfg: ModelConfig, rules: MeshRules, batch: int,
                 cache_tree: Any):
     """Sharding for cache/state pytrees (transformer / rwkv / zamba /
     whisper). When batch can't be sharded, the KV sequence dim takes 'data'
-    (split-K long-context decode)."""
+    (split-K long-context decode). The transformer's stacked K/V is
+    head-major, (L, B, Hkv, S, D): ``P(None, b, hkv, seq, None)``; zamba2's
+    and whisper's stacked K/V stay (L|ninv, B, S, Hkv, D)."""
     b = batch_spec(rules, batch)
     seq = None if b is not None else "data"
     hkv = _head_axis(rules, cfg.num_kv_heads)
     m = rules.model_axis
+    # the families transformer.py serves (models/api.py) stack K/V head-major
+    head_major = cfg.family in ("dense", "moe", "vlm")
 
     def spec(path, leaf):
         keys = [str(getattr(p, "key", getattr(p, "name", p))) for p in path]
         name = keys[-1]
         nd = len(leaf.shape)
         if any(k in ("k", "v", "cross_k", "cross_v") for k in keys):
-            # (L|ninv, B, S, Hkv, D) stacked — or (B, S, Hkv, D) for
-            # per-layer ring caches. When Hkv doesn't divide the model
-            # axis, shard the *sequence* over 'model' instead (split-K
-            # attention: softmax/AV reductions over the sharded S become
-            # small per-token all-reduces under GSPMD) — never replicate a
-            # multi-GB cache.
+            # (L, B, Hkv, S, D) or (L|ninv, B, S, Hkv, D) stacked — or
+            # (B, S, Hkv, D) for per-layer ring caches. When Hkv doesn't
+            # divide the model axis, shard the *sequence* over 'model'
+            # instead (split-K attention: softmax/AV reductions over the
+            # sharded S become small per-token all-reduces under GSPMD) —
+            # never replicate a multi-GB cache.
             if hkv is not None:
                 inner = P(b, seq, hkv, None)
             else:
                 s_axes = ("model",) if b is not None else ("data", "model")
                 inner = P(b, s_axes, None, None)
+            if nd == 5 and head_major:
+                return P(None, inner[0], inner[2], inner[1], None)
             if nd == 5:
                 return P(None, *inner)
             if inner[1] is not None and leaf.shape[1] % rules.tp != 0:

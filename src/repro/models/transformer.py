@@ -5,6 +5,12 @@ One homogeneous block is scanned over depth. Per-layer heterogeneity
 (per-layer attention window), so a single scan covers every family and the
 HLO stays O(1) in depth.
 
+The serving cache is one stacked, head-major pair (L, B, Hkv, S, D) per K
+and V. Prefill and decode carry it through the layer scan: each layer
+writes its new rows into it in place and reads its own layer back, so a
+step moves only the rows it writes and the layers it attends over.
+Window-sized ring caches (gemma2, unrolled) stay per-layer (B, S, Hkv, D).
+
 Public entry points (all pure):
   init_lm(rng, cfg)                                  -> params
   init_cache(cfg, batch, max_len, dtype)             -> cache pytree
@@ -99,6 +105,9 @@ def _use_ring(cfg: ModelConfig) -> bool:
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None):
+    """{"k", "v"}: each (num_layers, batch, num_kv_heads, max_len,
+    head_dim), head-major with the slot on axis 1; or, for a window-sized
+    ring cache, per-layer tuples of (batch, S, num_kv_heads, head_dim)."""
     dtype = _cache_dtype(cfg, dtype)
     if _use_ring(cfg):
         # per-layer cache: local layers keep only a window-sized ring
@@ -109,7 +118,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None):
             ks.append(jnp.zeros(shape, dtype))
             vs.append(jnp.zeros(shape, dtype))
         return {"k": tuple(ks), "v": tuple(vs)}
-    shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, batch, cfg.num_kv_heads, max_len, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -134,12 +143,15 @@ def _block_fn(
     mask: MaskSpec,
     window,
     kv=None,
+    layer=None,
     cache_positions=None,
     lengths=None,
     ep: Optional[M.EPInfo] = None,
     ring: bool = False,
 ):
     """One transformer block. ``window`` is a traced per-layer scalar.
+    ``kv`` is the stacked cache with ``layer`` this block's index in it,
+    or one layer's cache (``layer`` None).
     ``ring``: this layer's cache is a window-sized ring buffer (decode-only;
     the ring holds exactly the last ``ring_size`` tokens so the window mask
     reduces to slot-validity)."""
@@ -153,6 +165,7 @@ def _block_fn(
     attn_out, new_kv = L.apply_attention(
         bp["attn"], h, cfg, positions=positions, mask=mask,
         kv_cache=kv, cache_positions=cache_positions, lengths=lengths,
+        layer=layer,
     )
     if "norm_attn_post" in bp:
         attn_out = L.apply_norm(bp["norm_attn_post"], attn_out, cfg)
@@ -189,8 +202,7 @@ def _run_blocks(
 ):
     windows = layer_windows(cfg)
 
-    def body(carry, scanned, ring=False):
-        bp, window, kv = scanned
+    def block(x, bp, window, kv, layer=None, ring=False):
         fn = functools.partial(
             _block_fn, cfg=cfg, positions=positions, mask=mask,
             cache_positions=cache_positions, lengths=lengths, ep=ep,
@@ -198,50 +210,49 @@ def _run_blocks(
         )
         if remat:
             fn = _remat(fn, cfg)
-        h, new_kv = fn(bp, carry, window=window, kv=kv)
-        return h, new_kv
+        return fn(bp, x, window=window, kv=kv, layer=layer)
 
     if not cfg.scan_layers:
         # Unrolled (dry-run mode: exact cost_analysis; scan bodies are only
         # counted once by XLA's static cost model).
-        per_layer = cache is not None and isinstance(cache["k"], tuple)
-        ck = None if cache is None else (list(cache["k"]) if per_layer
-                                         else cache["k"])
-        cv = None if cache is None else (list(cache["v"]) if per_layer
-                                         else cache["v"])
-        max_s = max((a.shape[1] for a in ck), default=0) if per_layer else 0
+        ring_cache = cache is not None and isinstance(cache["k"], tuple)
+        if ring_cache:
+            ck, cv = list(cache["k"]), list(cache["v"])
+            max_s = max(a.shape[1] for a in ck)
+        kv = None if cache is None else (cache["k"], cache["v"])
         for i in range(cfg.num_layers):
             bp = jax.tree.map(lambda a: a[i], params["blocks"])
-            if cache is None:
-                kv = None
-            elif per_layer:
-                kv = (ck[i], cv[i])
+            if ring_cache:
+                x, (ck[i], cv[i]) = block(x, bp, windows[i], (ck[i], cv[i]),
+                                          ring=ck[i].shape[1] < max_s)
+            elif cache is None:
+                x, _ = block(x, bp, windows[i], None)
             else:
-                kv = (ck[i], cv[i])
-            ring = per_layer and kv[0].shape[1] < max_s
-            x, new_kv = body(x, (bp, windows[i], kv), ring=ring)
-            if cache is not None:
-                if per_layer:
-                    ck[i], cv[i] = new_kv
-                else:
-                    ck = ck.at[i].set(new_kv[0])
-                    cv = cv.at[i].set(new_kv[1])
+                x, kv = block(x, bp, windows[i], kv, layer=i)
         if cache is None:
             return x, None
-        if per_layer:
+        if ring_cache:
             return x, {"k": tuple(ck), "v": tuple(cv)}
-        return x, {"k": ck, "v": cv}
+        return x, {"k": kv[0], "v": kv[1]}
 
     if cache is None:
-        def body2(carry, scanned):
+        def train_body(x, scanned):
             bp, window = scanned
-            h, _ = body(carry, (bp, window, None))
-            return h, None
-        x, _ = lax.scan(body2, x, (params["blocks"], windows))
+            return block(x, bp, window, None)[0], None
+        x, _ = lax.scan(train_body, x, (params["blocks"], windows))
         return x, None
-    kvs = (params["blocks"], windows, (cache["k"], cache["v"]))
-    x, new_kv = lax.scan(body, x, kvs)
-    return x, {"k": new_kv[0], "v": new_kv[1]}
+
+    # the stacked cache is the scan's carry, updated in place layer by layer
+    def cached_body(carry, scanned):
+        x, k, v = carry
+        bp, window, layer = scanned
+        x, (k, v) = block(x, bp, window, (k, v), layer=layer)
+        return (x, k, v), None
+
+    (x, k, v), _ = lax.scan(
+        cached_body, (x, cache["k"], cache["v"]),
+        (params["blocks"], windows, jnp.arange(cfg.num_layers)))
+    return x, {"k": k, "v": v}
 
 
 # ---------------------------------------------------------------------------

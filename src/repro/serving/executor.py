@@ -22,7 +22,9 @@ Two execution regimes per executor:
   to a pow2 **bucket grid** and all same-bucket parts of an iteration run
   as ONE jitted slot-indexed call that updates the slotted cache in place
   (``jax.lax.dynamic_slice`` row gather + ``jax.lax.dynamic_update_slice``
-  row scatter under ``donate_argnums``), then the decode batch, with
+  row scatter under ``donate_argnums``), then the decode batch, which
+  writes its one new row per slot and layer into the donated cache in
+  place (the model carries the stacked cache through its layer scan), with
   exactly one ``block_until_ready`` and one device->host token transfer
   per iteration. Bucket padding never writes past the cache: rows pad
   LEFT by re-feeding already-prefilled prefix tokens (recomputing the
@@ -81,7 +83,8 @@ def _next_pow2(n: int) -> int:
 
 def _uniform_cache(cache) -> bool:
     """True for the slotted dict-of-arrays cache the bucketed kernels can
-    row-index: {"k","v"} with (L, B, S, H, D) leaves. Ring caches (tuple
+    row-index: {"k","v"} with head-major (L, B, H, S, D) leaves, the slot
+    on axis 1. Ring caches (tuple
     leaves) and stateful pytrees fall back to the scalar path."""
     return (isinstance(cache, dict) and set(cache.keys()) == {"k", "v"}
             and all(getattr(a, "ndim", 0) == 5 for a in cache.values()))

@@ -118,6 +118,23 @@ def test_served_decode_step_compiles_and_fits_one_chip(one_chip, qwen2_step):
     assert _bytes(compiled) < HBM_BYTES
 
 
+def test_served_decode_step_updates_the_cache_in_place(one_chip, qwen2_step):
+    """The decode step writes its rows into the donated cache: the cache
+    is aliased from input to output, and the step's scratch holds less
+    than one layer's K and V (no layer, let alone the cache, is copied)."""
+    kernels, params, cache = qwen2_step
+    s = functools.partial(_sds, one_chip)
+    m = kernels.decode_fn.lower(
+        params, cache, s((16,), jnp.int32),
+        s((16,), jnp.int32)).compile().memory_analysis()
+    k = cache["k"]
+    layer_kv = 2 * k.size // k.shape[0] * k.dtype.itemsize
+    assert layer_kv == 2 * 16 * 2048 * 2 * 128 * 2
+    assert m.temp_size_in_bytes < layer_kv
+    assert m.alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in cache.values())
+
+
 def test_served_prefill_step_compiles_for_v5e(one_chip, qwen2_step):
     kernels, params, cache = qwen2_step
     s = functools.partial(_sds, one_chip)
