@@ -11,6 +11,7 @@ import functools
 import jax
 
 from repro.kernels.chunked_prefill import chunked_prefill_attention
+from repro.kernels.decode_attention import decode_attention
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.wkv6 import wkv6_chunked
 from repro.kernels import ref
@@ -33,6 +34,19 @@ def chunked_prefill_op(q, k_cache, v_cache, starts, softcap=None,
     return chunked_prefill_attention(
         q, k_cache, v_cache, starts, softcap=softcap, window=window,
         bq=bq, bk=bk, interpret=auto_interpret())
+
+
+_decode_attention = jax.jit(decode_attention,
+                            static_argnames=("softcap", "interpret"))
+
+
+def decode_attention_op(q, k_cache, v_cache, layer, window, lengths,
+                        softcap=None):
+    """The model step's decode attention over its stacked cache (read
+    only). Interpret mode is a static argument of the jitted kernel, so
+    a trace made for one backend is never reused for the other."""
+    return _decode_attention(q, k_cache, v_cache, layer, window, lengths,
+                             softcap=softcap, interpret=auto_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

@@ -20,6 +20,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels import ops
+from repro.kernels.decode_attention import NO_WINDOW
+
 
 # ---------------------------------------------------------------------------
 # config
@@ -326,7 +329,9 @@ def apply_attention(
     * kv_cache (L, B, Hkv, Smax, D) with ``layer``: the model's stacked,
       head-major cache. The new rows are written into layer ``layer`` in
       place, that layer is read back and attended over; the whole stacked
-      pair is returned.
+      pair is returned. A decode step on one device reads each row's live
+      positions in place (``kernels/decode_attention.py``); under a mesh
+      of several devices, which may shard the cache, XLA attends.
     * cross_kv: attend over fixed K/V (encoder-decoder cross attention);
       no Q/K rope, no cache update.
     """
@@ -356,9 +361,17 @@ def apply_attention(
     if kv_cache is not None and layer is not None:
         k_cache = _stacked_cache_write(kv_cache[0], k, layer, cache_positions)
         v_cache = _stacked_cache_write(kv_cache[1], v, layer, cache_positions)
+        new_cache = (k_cache, v_cache)
+        if s == 1 and mask.kind == "lengths" and _one_device():
+            # decode: read each row's live positions in place (kernel)
+            window = NO_WINDOW if mask.window is None else mask.window
+            out = ops.decode_attention_op(
+                q[:, 0], k_cache, v_cache, layer, window, lengths,
+                softcap=cfg.attn_softcap)[:, None]
+            out = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
+            return out, new_cache
         k_all = _layer_view(k_cache, layer)
         v_all = _layer_view(v_cache, layer)
-        new_cache = (k_cache, v_cache)
     elif kv_cache is not None:
         k_cache, v_cache = kv_cache
         k_cache = _cache_write(k_cache, k, cache_positions)
@@ -378,6 +391,15 @@ def apply_attention(
                            unroll=cfg.attn_unroll_chunks)
     out = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     return out, new_cache
+
+
+def _one_device() -> bool:
+    """No mesh of several devices is set, so the cache lies whole on one
+    device. A program that shards the cache sets its mesh
+    (``jax.set_mesh``), and the compiler could partition the kernel only
+    by gathering the cache."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh.empty or mesh.size == 1
 
 
 def compute_kv(params, x, cfg: ModelConfig, positions=None, rope=False):

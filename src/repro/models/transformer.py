@@ -28,11 +28,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels.decode_attention import NO_WINDOW
 from repro.models import layers as L
 from repro.models import moe as M
 from repro.models.layers import MaskSpec, ModelConfig
-
-NO_WINDOW = jnp.iinfo(jnp.int32).max // 2  # "window" that never masks
 
 
 # ---------------------------------------------------------------------------
@@ -332,7 +331,12 @@ def prefill_chunk(params, cache, chunk, starts, cfg: ModelConfig, ep=None,
 
 def decode(params, cache, tokens, lengths, cfg: ModelConfig, ep=None):
     """One decode step. tokens (B,) int32 — the freshly sampled token, to be
-    written at position lengths[b]. Returns (logits (B, V), cache)."""
+    written at position lengths[b]. Returns (logits (B, V), cache).
+    With the stacked cache, attention reads only each row's live positions
+    (``kernels/decode_attention.py``); a row with a negative length does
+    not decode: its logits are to be ignored, and its write lands at the
+    slot's last position, which the slot's own later steps write before
+    they read it."""
     x = L.embed(params["embed"], tokens[:, None], cfg)
     b = x.shape[0]
     positions = lengths[:, None]

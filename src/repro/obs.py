@@ -11,7 +11,8 @@ kept in memory.
   stays JAX-free.
 * ``RECORDER`` is one process-wide, bounded ``Recorder``: a record per
   iteration (what the scheduler predicted and measured, the executor's
-  phase times) and a record per admitted request (its wait in the
+  phase times, the KV bytes of the live blocks its decode attention was
+  asked to read) and a record per admitted request (its wait in the
   queue). The counters are always on; ``reset()`` empties them.
 
 This module reads no clock: every time in it was stamped by its writer
@@ -62,6 +63,12 @@ class IterationRecord:
     measured: float          # duration the backend returned, seconds
     predicted: float         # Worker.plan_duration: the cost model's price
     phases: Optional[Phases]  # None where no executor ran the plan
+    # K and V bytes of the live blocks the decode call's attention was
+    # asked to read (the kernel's block rule), and the bytes of the whole
+    # stacked cache it reads from; None where no decode call ran on the
+    # fast path
+    kv_read_bytes: Optional[int] = None
+    kv_cache_bytes: Optional[int] = None
 
 
 @dataclasses.dataclass(slots=True)
@@ -88,24 +95,26 @@ class Recorder:
         self.iterations: collections.deque[IterationRecord] = \
             collections.deque(maxlen=self.MAX_ITERATIONS)
         self.requests: dict[int, RequestRecord] = {}
-        self._pending: Optional[tuple[object, Phases]] = None
+        self._pending: Optional[tuple] = None
 
     def reset(self) -> None:
         self.iterations.clear()
         self.requests.clear()
         self._pending = None
 
-    def executor_phases(self, plan, phases: Phases) -> None:
-        self._pending = (plan, phases)
+    def executor_phases(self, plan, phases: Phases,
+                        kv_read_bytes: Optional[int] = None,
+                        kv_cache_bytes: Optional[int] = None) -> None:
+        self._pending = (plan, phases, kv_read_bytes, kv_cache_bytes)
 
     def iteration(self, plan, wid: int, now: float, measured: float,
                   predicted: float) -> None:
         pending, self._pending = self._pending, None
-        phases = pending[1] if pending is not None and pending[0] is plan \
-            else None
+        if pending is None or pending[0] is not plan:
+            pending = (plan, None, None, None)
         kind = "mixed" if plan.prefill_parts else "decode"
         self.iterations.append(IterationRecord(
-            wid, now, kind, measured, predicted, phases))
+            wid, now, kind, measured, predicted, *pending[1:]))
 
     def admitted(self, rid: int, arrival: float, now: float) -> None:
         """A request's prefill started (a re-admission overwrites)."""

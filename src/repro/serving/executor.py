@@ -24,7 +24,8 @@ Two execution regimes per executor:
   (``jax.lax.dynamic_slice`` row gather + ``jax.lax.dynamic_update_slice``
   row scatter under ``donate_argnums``), then the decode batch, which
   writes its one new row per slot and layer into the donated cache in
-  place (the model carries the stacked cache through its layer scan), with
+  place (the model carries the stacked cache through its layer scan) and
+  attends over the decoding slots' live positions only, with
   exactly one ``block_until_ready`` and one device->host token transfer
   per iteration. Bucket padding never writes past the cache: rows pad
   LEFT by re-feeding already-prefilled prefix tokens (recomputing the
@@ -43,6 +44,7 @@ fall back to the scalar reference even under ``batched=True``.
 """
 from __future__ import annotations
 
+import collections
 import time
 from typing import Optional, Sequence
 
@@ -52,7 +54,9 @@ import numpy as np
 from jax import lax
 
 from repro import obs
+from repro.kernels import decode_attention
 from repro.models import api as model_api
+from repro.models.transformer import layer_windows_py
 from repro.perf import CostModel
 from repro.sched.backend import SlotExhausted
 from repro.serving.engine import IterationPlan, Worker
@@ -245,6 +249,19 @@ class RealExecutor:
         if self.fast and kernels is None:
             kernels = ExecutorKernels(self.api, max_slots, max_len)
         self.kernels = kernels
+        if self.fast:
+            # what the decode attention kernel is asked to read: blocks of
+            # ``_kv_block`` positions (K and V: ``_kv_block_bytes``), per
+            # layer window (layers that share a window read alike), out of
+            # the whole cache's ``_kv_cache_bytes``
+            k = self.cache["k"]
+            _, _, hkv, _, d = k.shape
+            self._kv_block = decode_attention.block_size(
+                max_len, hkv * d * k.dtype.itemsize)
+            self._kv_block_bytes = 2 * hkv * self._kv_block * d * \
+                k.dtype.itemsize
+            self._kv_cache_bytes = sum(a.nbytes for a in self.cache.values())
+            self._kv_windows = collections.Counter(layer_windows_py(cfg))
 
     # ------------------------------------------------------------ requests
     def register(self, req) -> None:
@@ -358,18 +375,31 @@ class RealExecutor:
         t3 = _stamp()
         return self._record(plan, t0, t1, t2, t3, t3)
 
-    @staticmethod
-    def _record(plan: IterationPlan, t0: float, t1: float, t2: float,
-                t3: float, t4: float) -> float:
+    def _record(self, plan: IterationPlan, t0: float, t1: float, t2: float,
+                t3: float, t4: float, kv_read: Optional[int] = None) -> float:
         obs.RECORDER.executor_phases(
-            plan, obs.Phases(t1 - t0, t2 - t1, t3 - t2, t4 - t3))
+            plan, obs.Phases(t1 - t0, t2 - t1, t3 - t2, t4 - t3), kv_read,
+            None if kv_read is None else self._kv_cache_bytes)
         return t4 - t0
 
-    def _prepare_calls(self, plan: IterationPlan) -> tuple[list, list]:
+    def _decode_kv_bytes(self, lengths: np.ndarray) -> int:
+        """K and V bytes of the live blocks the decode call's attention is
+        asked to read for these ``lengths``, by the kernel's own block
+        rule."""
+        return self._kv_block_bytes * sum(
+            n * int(decode_attention.live_blocks(
+                lengths, w, self._kv_block)[1].sum())
+            for w, n in self._kv_windows.items())
+
+    def _prepare_calls(self, plan: IterationPlan) \
+            -> tuple[list, list, Optional[int]]:
         """The iteration's calls, with their inputs on the device:
         ``[(bucket, rows, args) | (None, None, args)]`` (a prefill bucket
-        group, or the decode batch) and, per call, how its tokens are read
-        back."""
+        group, or the decode batch); per call, how its tokens are read
+        back; and the KV bytes of the live blocks the decode call's
+        attention is asked to read (None without one). A slot that does not
+        decode (free, or in prefill) gets length -1: the decode step reads
+        nothing for it."""
         groups: dict[int, list] = {}
         for req, take in plan.prefill_parts:
             bucket = self.kernels.bucket_for(take)
@@ -410,22 +440,25 @@ class RealExecutor:
                 jnp.asarray(chunk), jnp.asarray(slots), jnp.asarray(starts),
                 jnp.asarray(takes))))
             pending.append(("prefill", finals))
+        kv_read = None
         if plan.decode_reqs:
             dpairs = [(r, self._slot(r.rid)) for r in plan.decode_reqs]
             tokens = np.zeros(self.max_slots, np.int32)
-            lengths = np.array(self.lengths)
+            lengths = np.full(self.max_slots, -1, np.int32)
             for r, s in dpairs:
                 tokens[s] = self.generated[r.rid][-1]
+                lengths[s] = self.lengths[s]
             calls.append((None, None, (jnp.asarray(tokens),
                                        jnp.asarray(lengths))))
             pending.append(("decode", dpairs))
-        return calls, pending
+            kv_read = self._decode_kv_bytes(lengths)
+        return calls, pending, kv_read
 
     def _run_plan_fast(self, plan: IterationPlan) -> float:
         t0 = _stamp()
         with obs.span("backend.prepare", wid=self.wid):
             self.assign_slots(plan)
-            calls, pending = self._prepare_calls(plan)
+            calls, pending, kv_read = self._prepare_calls(plan)
         t1 = _stamp()
         tok_parts = []
         with obs.span("backend.dispatch"):
@@ -459,7 +492,7 @@ class RealExecutor:
                         self.generated[r.rid].append(int(host[off + s]))
                         self.lengths[s] += 1
                     off += self.max_slots
-        return self._record(plan, t0, t1, t2, t3, _stamp())
+        return self._record(plan, t0, t1, t2, t3, _stamp(), kv_read)
 
     def duration_fn(self):
         """Measured-wall-clock duration_fn for the Simulator."""

@@ -18,7 +18,9 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
+from repro.kernels import ops
 from repro.kernels.chunked_prefill import chunked_prefill_attention
+from repro.kernels.decode_attention import decode_attention
 from repro.kernels.paged_attention import paged_attention
 from repro.models import api as model_api
 from repro.serving.executor import ExecutorKernels
@@ -30,6 +32,8 @@ WIDTHS = [(12, 2, 128), (4, 1, 64), (8, 1, 128), (8, 2, 64), (8, 4, 64),
           (16, 4, 128)]
 # the page and chunk sizes perf/calibrate.py measures with
 PAGE, PAGES_PER_SEQ, BATCH, SEQ = 16, 8, 4, 256
+# qwen2-1.5b's served cache: 28 layers, 16 slots x 8192 positions
+LAYERS, SLOTS, MAX_LEN = 28, 16, 8192
 
 
 @pytest.fixture(scope="module")
@@ -92,16 +96,65 @@ def test_chunked_prefill_compiles_for_v5e(one_chip, hq, hkv, d):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("hq,hkv,d,dtype,softcap", [
+    (12, 2, 128, jnp.bfloat16, None),           # qwen2-1.5b, as served
+    (12, 2, 128, jnp.float8_e4m3fn, 50.0),
+    (32, 32, 128, jnp.bfloat16, None),          # MHA: a smaller block
+    (8, 4, 256, jnp.bfloat16, 50.0),            # gemma2
+    (32, 32, 64, jnp.bfloat16, None),           # stablelm: the grid form
+    (12, 2, 64, jnp.float8_e4m3fn, None),
+])
+def test_decode_attention_compiles_for_v5e(one_chip, hq, hkv, d, dtype,
+                                           softcap):
+    """At 16 slots x 8192 positions; qwen2-1.5b's 28 layers at its
+    widths, 4 layers elsewhere (the layer count only sizes the array)."""
+    s = functools.partial(_sds, one_chip)
+    layers = LAYERS if (hq, hkv, d) == (12, 2, 128) else 4
+    cache = s((layers, SLOTS, hkv, MAX_LEN, d), dtype)
+    i32 = s((), jnp.int32)
+    hlo = _kernel_hlo(
+        functools.partial(decode_attention, softcap=softcap,
+                          interpret=False),
+        s((SLOTS, hq, d), jnp.bfloat16), cache, cache, i32, i32,
+        s((SLOTS,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
 @pytest.fixture(scope="module")
-def qwen2_step(one_chip):
-    """qwen2-1.5b at its published config: entry points and the shapes
-    of its params and a 16-slot x 2048-position cache, on one chip."""
+def mosaic():
+    """The program's kernels compiled as on the chip: the test host's
+    default backend is the CPU, where ``auto_interpret`` says interpret."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "auto_interpret", lambda: False)
+        yield
+
+
+def _qwen2(one_chip, max_len):
     api = model_api.build(get_config("qwen2-1.5b"))
-    kernels = ExecutorKernels(api, max_slots=16, max_len=2048)
+    kernels = ExecutorKernels(api, max_slots=SLOTS, max_len=max_len)
     params = _placed(one_chip,
                      jax.eval_shape(api.init, jax.random.PRNGKey(0)))
-    cache = _placed(one_chip, api.cache_spec(16, 2048))
+    cache = _placed(one_chip, api.cache_spec(SLOTS, max_len))
     return kernels, params, cache
+
+
+@pytest.fixture(scope="module")
+def qwen2_step(one_chip, mosaic):
+    """qwen2-1.5b at its published config: entry points and the shapes
+    of its params and a 16-slot x 2048-position cache, on one chip."""
+    return _qwen2(one_chip, 2048)
+
+
+@pytest.fixture(scope="module")
+def qwen2_decode_served(one_chip, mosaic):
+    """The decode step compiled at the served 16 x 8192 cache, and that
+    cache's shapes."""
+    kernels, params, cache = _qwen2(one_chip, MAX_LEN)
+    s = functools.partial(_sds, one_chip)
+    compiled = kernels.decode_fn.lower(
+        params, cache, s((SLOTS,), jnp.int32),
+        s((SLOTS,), jnp.int32)).compile()
+    return compiled, cache
 
 
 def _bytes(compiled) -> float:
@@ -142,3 +195,27 @@ def test_served_prefill_step_compiles_for_v5e(one_chip, qwen2_step):
     compiled = kernels.prefill_fn(512, 1).lower(
         params, cache, s((1, 512), jnp.int32), one, one, one).compile()
     assert _bytes(compiled) < HBM_BYTES
+
+
+def test_served_decode_step_attends_with_the_kernel(qwen2_decode_served):
+    compiled, _ = qwen2_decode_served
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _bytes(compiled) < HBM_BYTES
+
+
+def test_served_decode_step_keeps_the_cache_in_place(qwen2_decode_served):
+    """The kernel reads the carried cache where it lies: the cache stays
+    aliased, no op makes a copy of the whole (L, B, Hkv, S, D) K or V,
+    and the step's scratch stays under one layer's K and V."""
+    compiled, cache = qwen2_decode_served
+    k = cache["k"]
+    whole = "[" + ",".join(map(str, k.shape)) + "]"
+    copies = [line for line in compiled.as_text().splitlines()
+              if whole in line.split("=", 1)[-1].split("(", 1)[0]
+              and " copy(" in line]
+    assert not copies, copies[:2]
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in cache.values())
+    assert m.temp_size_in_bytes < 2 * k.size // k.shape[0] * \
+        k.dtype.itemsize
